@@ -20,12 +20,13 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
-from repro.core.live import LiveConfig, LiveEngine
+from repro.core.live import LiveConfig, LiveEngine, use_compile_cache
 from repro.core.query import Query, QueryWork
 from repro.core.sla import Policy, ServiceLevel, SLAConfig
 
 
 def main():
+    use_compile_cache()
     eng = LiveEngine(LiveConfig(
         policy=Policy.AUTO,
         cf_startup_s=0.2,

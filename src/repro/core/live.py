@@ -28,10 +28,12 @@ Used by examples/serve_sla.py, tests/test_live.py, tests/test_system.py.
 """
 from __future__ import annotations
 
+import os
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field, replace
+from pathlib import Path
 from typing import Any, Optional
 
 import jax
@@ -55,6 +57,24 @@ from .scheduler import QueryCoordinator, ServiceLayer, unpack_fused
 from .sla import Policy, ServiceLevel, SLAConfig
 
 
+#: where a live entry point keeps JAX's persistent compilation cache when
+#: JAX_COMPILATION_CACHE_DIR is unset: one fixed, gitignored directory in
+#: the checkout (the path is part of the cache key, so it never moves)
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def use_compile_cache() -> str:
+    """Turn on the persistent compilation cache for a live entry point
+    and return its directory. JAX reads JAX_COMPILATION_CACHE_DIR itself;
+    only when that is unset is the cache pointed at COMPILE_CACHE_DIR.
+    Call before the first compile."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(COMPILE_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def _prompt_inputs(cfg, batch: int, prompt_tokens: int, seed: int):
     """Prompt batch + frontend/encoder kwargs for one prefill call. The
     SHAPES depend only on (arch, batch, prompt_tokens) — the warm-up and
@@ -76,10 +96,12 @@ def _prompt_inputs(cfg, batch: int, prompt_tokens: int, seed: int):
 
 @dataclass(frozen=True)
 class _LiveModel:
-    """One arch's jitted entry points. ``prefill(params, toks, kw)``
-    returns (next token, decode cache); ``decode(params, cache, tok)``
-    returns (next token, new cache). Greedy sampling is inside the jit,
-    so one stage is exactly one compiled call per token."""
+    """One arch's entry points, compiled for one batch size.
+    ``prefill(params, toks, kw)`` returns (next token, decode cache,
+    last-position logits); ``decode(params, cache, tok)`` returns (next
+    token, new cache). Both are ahead-of-time compiled executables, so
+    ``prefill.as_text()`` is the very program that serves. Greedy
+    sampling is inside, so one stage is exactly one call per token."""
 
     cfg: Any
     params: dict
@@ -88,76 +110,85 @@ class _LiveModel:
 
 
 class _ModelPool:
-    """Jitted reduced models shared by every live pool, warmed OUTSIDE
-    the billed window: the first ``ensure`` for an (arch, batch) shape
-    runs one throwaway prefill + decode step and blocks until compiled,
-    so no stage wall-clock ever includes XLA compile time (the
-    first-query billing skew of the old engine). Compile seconds are
-    recorded per shape in ``compile_s`` for observability."""
+    """Compiled models shared by every live pool, warmed OUTSIDE the
+    billed window: the first ``ensure`` for an (arch, batch) shape
+    compiles prefill + decode and runs one throwaway step of each, so no
+    stage wall-clock ever includes XLA compile time (the first-query
+    billing skew of the old engine). Compile seconds are recorded per
+    shape in ``compile_s`` for observability.
+
+    ``published_widths`` builds each arch's published config in bf16
+    (what a chip serves); otherwise the reduced float32 config."""
 
     #: lock contract — enforced statically by reprolint RL001 and at
     #: runtime by repro.core.sanitize (REPRO_SANITIZE=1); one registry
     #: feeds both, so the checks cannot drift apart.
     _GUARDED_BY = {
+        "_archs": "_lock",
         "_models": "_lock",
-        "_warm": "_lock",
         "compile_s": "_lock",
     }
 
-    def __init__(self, prompt_tokens: int, decode_tokens: int):
+    def __init__(self, prompt_tokens: int, decode_tokens: int, *,
+                 published_widths: bool = False, impl: str = "jnp"):
         self.prompt_tokens = prompt_tokens
         self.decode_tokens = decode_tokens
-        self._models: dict[str, _LiveModel] = {}
-        self._warm: set[tuple[str, int]] = set()
+        self.published_widths = published_widths
+        self.impl = impl
+        self.dtype = jnp.bfloat16 if published_widths else jnp.float32
+        # arch -> (cfg, params, jitted prefill, jitted decode)
+        self._archs: dict[str, tuple] = {}
+        self._models: dict[tuple[str, int], _LiveModel] = {}
         self.compile_s: dict[tuple[str, int], float] = {}
-        self._lock = sanitize.ordered_lock(
-            "_ModelPool._lock", threading.Lock()
-        )
+        self._lock = threading.Lock()  # nests no other lock
 
     @property
     def kv_len(self) -> int:
-        return self.prompt_tokens + self.decode_tokens + 8
+        """Decode-cache context, rounded up to a multiple of 128 so the
+        cache (``kv_len + 128`` slots) tiles into the decode kernel's
+        128-slot blocks instead of falling back to the jnp path."""
+        return -(-(self.prompt_tokens + self.decode_tokens + 8) // 128) * 128
 
-    def _build(self, arch: str) -> _LiveModel:
-        cfg = get_config(arch, reduced=True)
-        model = LM(cfg)
-        params = model.init(jax.random.PRNGKey(0), dtype=jnp.float32)
+    def _build(self, arch: str) -> tuple:
+        cfg = get_config(arch, reduced=not self.published_widths)
+        model = LM(cfg, impl=self.impl)
+        dtype = self.dtype
+        params = model.init(jax.random.PRNGKey(0), dtype=dtype)
         kv_len = self.kv_len
 
         @jax.jit
         def prefill(params, toks, kw):
             logits, cache = model.prefill(
-                params, toks, kv_len=kv_len, dtype=jnp.float32, **kw
+                params, toks, kv_len=kv_len, dtype=dtype, **kw
             )
-            return jnp.argmax(logits, -1)[:, None], cache
+            return jnp.argmax(logits, -1)[:, None], cache, logits
 
         @jax.jit
         def decode(params, cache, tok):
-            logits, cache = model.decode_step(
-                params, cache, tok, dtype=jnp.float32
-            )
+            logits, cache = model.decode_step(params, cache, tok, dtype=dtype)
             return jnp.argmax(logits, -1)[:, None], cache
 
-        return _LiveModel(cfg=model.cfg, params=params,
-                          prefill=prefill, decode=decode)
+        return model.cfg, params, prefill, decode
 
     def ensure(self, arch: str, batch: int) -> _LiveModel:
         """Return the arch's entry points, compiled for this batch."""
         with self._lock:
-            lm = self._models.get(arch)
-            if lm is None:
-                lm = self._models[arch] = self._build(arch)
             key = (arch, batch)
-            if key in self._warm:
+            lm = self._models.get(key)
+            if lm is not None:
                 return lm
+            if arch not in self._archs:
+                self._archs[arch] = self._build(arch)
+            cfg, params, prefill, decode = self._archs[arch]
             t0 = time.monotonic()
-            toks, kw = _prompt_inputs(lm.cfg, batch, self.prompt_tokens, 0)
-            tok, cache = lm.prefill(lm.params, toks, kw)
-            if self.decode_tokens:
-                tok, cache = lm.decode(lm.params, cache, tok)
+            toks, kw = _prompt_inputs(cfg, batch, self.prompt_tokens, 0)
+            prefill = prefill.lower(params, toks, kw).compile()
+            tok, cache, _ = prefill(params, toks, kw)
+            decode = decode.lower(params, cache, tok).compile()
+            tok, cache = decode(params, cache, tok)
             jax.block_until_ready(tok)
             self.compile_s[key] = time.monotonic() - t0
-            self._warm.add(key)
+            lm = self._models[key] = _LiveModel(cfg, params, prefill, decode)
             return lm
 
 
@@ -375,7 +406,7 @@ class LiveExecutor(ClusterExecutor):
             toks, kw = _prompt_inputs(
                 lm.cfg, batch, q.work.prompt_tokens, seed=q.qid
             )
-            tok, cache = lm.prefill(lm.params, toks, kw)
+            tok, cache, _ = lm.prefill(lm.params, toks, kw)
             jax.block_until_ready(tok)
             eng._save_ckpt(q, DecodeCheckpoint(cache, tok, 0))
             return
@@ -605,10 +636,17 @@ class LiveConfig:
     cf_startup_s: float = 0.3
     vm_price: float = 1.0  # $ per worker-second (multiplier base)
     cf_price_multiplier: float = 10.0
-    # every live query runs this reduced shape (q.work is normalized at
-    # submit — the legacy engine did the same implicitly)
+    # every live query runs this shape (q.work is normalized at submit —
+    # the legacy engine did the same implicitly)
     prompt_tokens: int = 32
     decode_tokens: int = 4
+    #: build each arch at its published widths in bf16 — what a chip
+    #: serves; False keeps the reduced float32 configs the CPU tests run
+    published_widths: bool = False
+    #: attention of the live models (models/layers.py SDPA_IMPL): "jnp",
+    #: or "pallas" — the kernels, compiled on a TPU and interpreted on
+    #: any other backend
+    impl: str = "jnp"
     #: decode chunk (= stage) size: the preemption/spill granularity
     decode_chunk_tokens: int = 2
     #: live calibration loop (core/calibration.py): fit each pool's
@@ -659,7 +697,10 @@ class LiveEngine:
 
     def __init__(self, cfg: LiveConfig):
         self.cfg = cfg
-        self.models = _ModelPool(cfg.prompt_tokens, cfg.decode_tokens)
+        self.models = _ModelPool(
+            cfg.prompt_tokens, cfg.decode_tokens,
+            published_widths=cfg.published_widths, impl=cfg.impl,
+        )
         self.done: list[Query] = []
         self.failed: list[Query] = []
         self._lock = threading.RLock()  # service layer + result sinks
@@ -721,8 +762,8 @@ class LiveEngine:
         return self.coordinator.vm.run_queue_len
 
     def live_work(self, work: QueryWork) -> QueryWork:
-        """Normalize a work descriptor to the reduced shape the live
-        models actually run (every query shares one jit footprint)."""
+        """Normalize a work descriptor to the shape the live models
+        actually run (every query of a batch size shares one program)."""
         return replace(
             work,
             kind="serve",

@@ -107,7 +107,6 @@ def guard(obj, attr: str) -> None:
 #: analysis that derived it.
 LOCK_RANKS = {
     "LiveExecutor._mu": 0,
-    "_ModelPool._lock": 0,
     "CrossPoolFusionIndex._lock": 1,
 }
 

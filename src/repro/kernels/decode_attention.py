@@ -23,11 +23,11 @@ NEG_INF = -1e30
 
 
 def _decode_kernel(
-    qpos_ref,  # (1, 1) current absolute position (= lengths)
+    len_ref,  # (B,) SMEM scalar prefetch: current absolute positions
     q_ref,  # (1, 1, G, hd)
     k_ref,  # (1, 1, bk, hd)
     v_ref,  # (1, 1, bk, hd)
-    pid_ref,  # (1, bk) pos_ids of the slots
+    pid_ref,  # (1, 1, bk) pos_ids of the slots
     o_ref,  # (1, 1, G, hd)
     m_scr,  # (G, 1)
     l_scr,  # (G, 1)
@@ -49,8 +49,8 @@ def _decode_kernel(
     q = q_ref[0, 0].astype(F32)  # (G, hd)
     k = k_ref[0, 0].astype(F32)  # (bk, hd)
     v = v_ref[0, 0].astype(F32)  # (bk, hd)
-    pid = pid_ref[0]  # (bk,) int32
-    qpos = qpos_ref[0, 0]  # scalar int32
+    pid = pid_ref[0]  # (1, bk) int32
+    qpos = len_ref[pl.program_id(0)]  # scalar int32
 
     valid = (pid >= 0) & (pid <= qpos)
     if window > 0:
@@ -62,7 +62,7 @@ def _decode_kernel(
     s = s * scale
     if softcap > 0:
         s = softcap * jnp.tanh(s / softcap)
-    s = jnp.where(valid[None, :], s, NEG_INF)
+    s = jnp.where(valid, s, NEG_INF)
 
     m_prev = m_scr[..., 0]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))  # (G,)
@@ -106,7 +106,9 @@ def decode_attention(
     qr = q.reshape(B, K, G, hd)
     kr = jnp.moveaxis(k, 1, 2)  # (B, K, Smax, hd)
     vr = jnp.moveaxis(v, 1, 2)
-    qpos = lengths.reshape(B, 1).astype(jnp.int32)
+    # a unit middle axis keeps the pos_ids block's last two dims
+    # (1, block_k) tile-legal for any B; lengths ride in SMEM
+    pid = pos_ids.reshape(B, 1, Smax)
 
     kernel = functools.partial(
         _decode_kernel,
@@ -117,21 +119,29 @@ def decode_attention(
     )
     out = pl.pallas_call(
         kernel,
-        grid=(B, K, nk),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, j: (b, 0)),
-            pl.BlockSpec((1, 1, G, hd), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, hd), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, block_k, hd), lambda b, h, j: (b, h, j, 0)),
-            pl.BlockSpec((1, block_k), lambda b, h, j: (b, j)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, h, j: (b, h, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, K, nk),
+            in_specs=[
+                pl.BlockSpec((1, 1, G, hd), lambda b, h, j, n: (b, h, 0, 0)),
+                pl.BlockSpec(
+                    (1, 1, block_k, hd), lambda b, h, j, n: (b, h, j, 0)
+                ),
+                pl.BlockSpec(
+                    (1, 1, block_k, hd), lambda b, h, j, n: (b, h, j, 0)
+                ),
+                pl.BlockSpec((1, 1, block_k), lambda b, h, j, n: (b, 0, j)),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, 1, G, hd), lambda b, h, j, n: (b, h, 0, 0)
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((G, 1), F32),
+                pltpu.VMEM((G, 1), F32),
+                pltpu.VMEM((G, hd), F32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((B, K, G, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((G, 1), F32),
-            pltpu.VMEM((G, 1), F32),
-            pltpu.VMEM((G, hd), F32),
-        ],
         interpret=interpret,
-    )(qpos, qr, kr, vr, pos_ids)
+    )(lengths.astype(jnp.int32), qr, kr, vr, pid)
     return out.reshape(B, H, hd)

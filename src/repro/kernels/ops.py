@@ -1,11 +1,14 @@
 """Jitted public wrappers around the Pallas kernels.
 
-* On TPU the kernels run compiled (interpret=False); on this CPU
-  container they run in interpret mode — same kernel body, Python
-  evaluation — which is how tests validate them.
+* On TPU the kernels run compiled (interpret=False); on any other
+  backend they run in interpret mode — same kernel body, evaluated by
+  XLA — which is how the CPU tests validate them. The choice is made
+  when a wrapper is traced (``_interpret``), never at import: importing
+  this module touches no JAX backend, so it cannot claim the chip.
 * ``sdpa_flash`` registers itself as the "pallas" SDPA implementation in
   models/layers.py, so any model can switch its attention inner loop to
-  the kernel with ``LM(cfg, impl="pallas")``.
+  the kernel with ``LM(cfg, impl="pallas")``; building such a model
+  imports this module.
 * Training differentiability: flash_attention gets a custom_vjp whose
   backward rematerializes through the jnp oracle (exact same math). The
   dedicated TPU backward kernel is future work; serving (the paper's
@@ -25,14 +28,17 @@ from .flash_attention import flash_attention
 from .ref import decode_attention_ref, flash_attention_ref, ssd_scan_ref
 from .ssd_scan import ssd_scan
 
-ON_TPU = any(d.platform == "tpu" for d in jax.devices())
-INTERPRET = not ON_TPU
+
+def _interpret() -> bool:
+    """Interpret mode unless JAX's default backend is a TPU."""
+    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def flash_attention_diff(q, k, v, causal=True, window=0, softcap=0.0):
     return flash_attention(
-        q, k, v, causal=causal, window=window, softcap=softcap, interpret=INTERPRET
+        q, k, v, causal=causal, window=window, softcap=softcap,
+        interpret=_interpret(),
     )
 
 
@@ -70,7 +76,7 @@ def sdpa_flash(q, k, v, q_pos, k_pos, window, causal, cap):
         lengths = q_pos[:, 0]
         return decode_attention(
             q[:, 0], k, v, k_pos, lengths,
-            window=win, softcap=capf, interpret=INTERPRET,
+            window=win, softcap=capf, interpret=_interpret(),
         )[:, None]
     if Sq % 128 == 0 and k.shape[1] % 128 == 0 and Sq == k.shape[1]:
         return flash_attention_diff(q, k, v, causal, win, capf)

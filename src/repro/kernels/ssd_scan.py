@@ -8,8 +8,10 @@ term is two (Q,N)x(N,Q) / (Q,Q)x(Q,P) MXU matmuls; Q=128 keeps every
 matmul dim hardware-aligned.
 
 Layouts (head-major so one program owns one head's sequence):
-  x   (B, H, nc, Q, P)   dtA (B, H, nc, Q)   dt (B, H, nc, Q)
-  B_  (B, H, nc, Q, N)   C_  (B, H, nc, Q, N)
+  x   (B, H, nc, Q, P)   cs (B, H, nc, 1, Q) and (B, H, nc, Q, 1)
+  B_*dt (B, H, nc, Q, N) f32   C_ (B, H, nc, Q, N)
+where cs is the chunk-inclusive cumsum of dt*A. Every block's last two
+dims are either full or (8,128)-aligned, so any number of chunks tiles.
 Outputs: y (B, H, nc, Q, P), final state (B, H, P, N) f32.
 """
 from __future__ import annotations
@@ -26,9 +28,9 @@ F32 = jnp.float32
 
 def _ssd_kernel(
     x_ref,  # (1, 1, 1, Q, P)
-    dta_ref,  # (1, 1, 1, Q)
-    dt_ref,  # (1, 1, 1, Q)
-    b_ref,  # (1, 1, 1, Q, N)
+    csr_ref,  # (1, 1, 1, 1, Q) chunk-inclusive cumsum of dt*A, as a row
+    csc_ref,  # (1, 1, 1, Q, 1) the same, as a column
+    b_ref,  # (1, 1, 1, Q, N) f32, dt folded in
     c_ref,  # (1, 1, 1, Q, N)
     y_ref,  # (1, 1, 1, Q, P)
     fs_ref,  # (1, 1, P, N) final state
@@ -43,40 +45,38 @@ def _ssd_kernel(
         state[...] = jnp.zeros_like(state)
 
     x = x_ref[0, 0, 0].astype(F32)  # (Q, P)
-    dta = dta_ref[0, 0, 0].astype(F32)  # (Q,)
-    dt = dt_ref[0, 0, 0].astype(F32)  # (Q,)
-    B_ = b_ref[0, 0, 0].astype(F32)  # (Q, N)
+    cs_r = csr_ref[0, 0, 0]  # (1, Q)
+    cs_c = csc_ref[0, 0, 0]  # (Q, 1)
+    Bd = b_ref[0, 0, 0]  # (Q, N) = B * dt
     C_ = c_ref[0, 0, 0].astype(F32)  # (Q, N)
+    Q = x.shape[0]
 
-    cs = jnp.cumsum(dta)  # (Q,) inclusive
-    # intra-chunk: scores[q,k] = C_q . B_k, decay L[q,k] = exp(cs_q - cs_k)
+    # intra-chunk: y_q = sum_{k<=q} (C_q . B_k) dt_k exp(cs_q - cs_k) x_k
     scores = jax.lax.dot_general(
-        C_, B_, (((1,), (1,)), ((), ())), preferred_element_type=F32
+        C_, Bd, (((1,), (1,)), ((), ())), preferred_element_type=F32
     )  # (Q, Q)
-    diff = cs[:, None] - cs[None, :]
-    Q = cs.shape[0]
     tri = (
         jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
         >= jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
     )
-    L = jnp.where(tri, jnp.exp(diff), 0.0)
-    M = scores * L * dt[None, :]
+    L = jnp.where(tri, jnp.exp(cs_c - cs_r), 0.0)
     y = jax.lax.dot_general(
-        M, x, (((1,), (0,)), ((), ())), preferred_element_type=F32
+        scores * L, x, (((1,), (0,)), ((), ())), preferred_element_type=F32
     )  # (Q, P)
     # inter-chunk: y += (C * exp(cs)) @ state^T
-    Cw = C_ * jnp.exp(cs)[:, None]
+    Cw = C_ * jnp.exp(cs_c)
     y += jax.lax.dot_general(
         Cw, state[...], (((1,), (1,)), ((), ())), preferred_element_type=F32
     )
     y_ref[0, 0, 0] = y.astype(y_ref.dtype)
 
-    # state update: state = exp(cs_last) * state + x^T @ (B * w)
-    w = jnp.exp(cs[-1] - cs) * dt  # (Q,)
+    # state update: state = exp(cs_last) * state + x^T @ (B dt exp(cs_last - cs))
+    cs_last = cs_r[:, Q - 1:]  # (1, 1)
     upd = jax.lax.dot_general(
-        x, B_ * w[:, None], (((0,), (0,)), ((), ())), preferred_element_type=F32
+        x, Bd * jnp.exp(cs_last - cs_c), (((0,), (0,)), ((), ())),
+        preferred_element_type=F32,
     )  # (P, N)
-    state[...] = jnp.exp(cs[-1]) * state[...] + upd
+    state[...] = jnp.exp(cs_last) * state[...] + upd
 
     @pl.when(ic == num_chunks - 1)
     def _final():
@@ -106,8 +106,11 @@ def ssd_scan(
 
     xr = head_major(x)
     dtr = head_major(dt[..., None])[..., 0]  # (B,H,nc,Q)
-    dta = dtr * A[None, :, None, None].astype(F32)
-    Br = head_major(B_)
+    # the chunk cumsum runs here: Mosaic lowers no in-kernel cumsum, and
+    # no (1,Q) -> (Q,1) relayout, so the kernel gets both orientations
+    cs = jnp.cumsum(dtr * A[None, :, None, None].astype(F32), axis=-1)
+    cs_row, cs_col = cs[..., None, :], cs[..., :, None]
+    Bd = head_major(B_.astype(F32) * dt[..., None].astype(F32))
     Cr = head_major(C_)
 
     kernel = functools.partial(_ssd_kernel, num_chunks=nc)
@@ -116,8 +119,8 @@ def ssd_scan(
         grid=(B, H, nc),
         in_specs=[
             pl.BlockSpec((1, 1, 1, Q, P), lambda b, h, c: (b, h, c, 0, 0)),
-            pl.BlockSpec((1, 1, 1, Q), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1, 1, 1, Q), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, 1, 1, 1, Q), lambda b, h, c: (b, h, c, 0, 0)),
+            pl.BlockSpec((1, 1, 1, Q, 1), lambda b, h, c: (b, h, c, 0, 0)),
             pl.BlockSpec((1, 1, 1, Q, N), lambda b, h, c: (b, h, c, 0, 0)),
             pl.BlockSpec((1, 1, 1, Q, N), lambda b, h, c: (b, h, c, 0, 0)),
         ],
@@ -131,6 +134,6 @@ def ssd_scan(
         ],
         scratch_shapes=[pltpu.VMEM((P, N), F32)],
         interpret=interpret,
-    )(xr, dta, dtr, Br, Cr)
+    )(xr, cs_row, cs_col, Bd, Cr)
     y = jnp.moveaxis(y.reshape(B, H, S, P), 1, 2)  # (B,S,H,P)
     return y, fs

@@ -9,25 +9,19 @@ from __future__ import annotations
 import jax
 
 
-def mesh_axis_kwargs(n_axes: int) -> dict:
-    """`axis_types=Auto` where supported; older jax predates AxisType
-    (explicit-sharding era) and already treats every axis as auto."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     """16x16 chips per pod ("data","model"); 2 pods adds a leading "pod"
     axis. v5e pod slice = 256 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **mesh_axis_kwargs(len(axes)))
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_local_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
     """Small mesh over whatever devices exist (CPU tests)."""
     return jax.make_mesh(
-        (data, model), ("data", "model"), **mesh_axis_kwargs(2)
+        (data, model), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2,
     )

@@ -29,7 +29,8 @@ def _coll_out(x):
 F32 = jnp.float32
 
 # Pluggable scaled-dot-product-attention implementations. kernels/ops.py
-# registers "pallas" on import; "jnp" is the oracle/default.
+# registers "pallas" (sdpa_impl imports it on first use); "jnp" is the
+# oracle/default.
 SDPA_IMPL: dict = {}
 
 
@@ -165,8 +166,22 @@ def _sdpa_jnp(q, k, v, q_pos, k_pos, window, causal, cap) -> jax.Array:
 SDPA_IMPL["jnp"] = _sdpa_jnp
 
 
+def sdpa_impl(impl: str):
+    """The SDPA implementation registered as ``impl``. Asking for
+    "pallas" imports kernels/ops.py, which registers it; any other name
+    that nobody registered raises instead of running the jnp path."""
+    if impl == "pallas" and impl not in SDPA_IMPL:
+        from ..kernels import ops  # noqa: F401 — registers "pallas"
+    try:
+        return SDPA_IMPL[impl]
+    except KeyError:
+        raise ValueError(
+            f"unknown attention impl {impl!r}; known: {sorted(SDPA_IMPL)}"
+        ) from None
+
+
 def sdpa(q, k, v, *, q_pos, k_pos, window, causal, cap, impl: str = "jnp"):
-    return SDPA_IMPL.get(impl, _sdpa_jnp)(q, k, v, q_pos, k_pos, window, causal, cap)
+    return sdpa_impl(impl)(q, k, v, q_pos, k_pos, window, causal, cap)
 
 
 def quantize_kv(t: jax.Array) -> tuple[jax.Array, jax.Array]:

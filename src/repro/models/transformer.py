@@ -32,6 +32,7 @@ from .layers import (
     moe_apply,
     moe_decl,
     rms_norm,
+    sdpa_impl,
     softcap,
 )
 from .params import ParamDecl, axes_tree, init_tree, shape_tree, stacked
@@ -83,6 +84,7 @@ class LM:
     def __init__(self, cfg: ModelConfig, impl: str = "jnp", scan_unroll: bool = False,
                  kv_quant: bool = False):
         self.cfg = cfg
+        sdpa_impl(impl)  # unknown names raise here; "pallas" registers
         self.impl = impl
         self.kv_quant = kv_quant  # int8 KV cache (serving)
         # unroll=True inlines every layer into the HLO: used by the

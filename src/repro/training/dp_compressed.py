@@ -78,18 +78,10 @@ def make_dp_train_step(
             jax.tree.map(lambda _: rep, state),
             {"loss": rep, "grad_norm": rep, "lr": rep},
         )
-        if hasattr(jax, "shard_map"):
-            sm = jax.shard_map(
-                shard_body, mesh=mesh, in_specs=in_specs,
-                out_specs=out_specs, check_vma=False,
-            )
-        else:  # pre-0.5 jax: experimental spelling, check_rep kwarg
-            from jax.experimental.shard_map import shard_map as _shard_map
-
-            sm = _shard_map(
-                shard_body, mesh=mesh, in_specs=in_specs,
-                out_specs=out_specs, check_rep=False,
-            )
+        sm = jax.shard_map(
+            shard_body, mesh=mesh, in_specs=in_specs,
+            out_specs=out_specs, check_vma=False,
+        )
         return sm(state, batch)
 
     return step

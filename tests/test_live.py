@@ -689,3 +689,50 @@ def test_elastic_startup_sleep_does_not_block_shutdown():
         f"shutdown took {took:.1f}s — the provisioning sleep is not "
         f"interruptible"
     )
+
+
+# ---------------------------------------------------------------------------
+# the chip smoke's serving phase, on CPU: reduced widths, Pallas kernels
+# in interpret mode
+# ---------------------------------------------------------------------------
+
+def _load_chip_smoke():
+    import importlib.util
+
+    path = Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_chip_smoke_serving_phase_on_reduced_pallas_models(monkeypatch):
+    """chip_smoke.py's serve phase with the reduced qwen2-0.5b and
+    impl="pallas" (interpret mode here): all three levels at batch 1 and
+    4 finish with complete, conserved stage traces, one BEST_EFFORT query
+    is preempted, and no attention call falls back to the jnp path."""
+    from repro.models import layers
+
+    smoke = _load_chip_smoke()
+
+    def no_fallback(*args):
+        raise AssertionError("pallas attention fell back to the jnp path")
+
+    monkeypatch.setattr(layers, "_sdpa_jnp", no_fallback)
+    eng = LiveEngine(smoke.live_config(published_widths=False))
+    assert eng.models.impl == "pallas" and not eng.models.published_widths
+    try:
+        smoke.phase_build(eng)
+        qs = smoke.phase_serve(eng, timeout_s=120.0)
+    finally:
+        eng.shutdown()
+    assert {q.sla for q in qs} == set(ServiceLevel)
+    assert {q.work.batch for q in qs} == set(smoke.BATCHES)
+    assert all(q.state == "done" and q.error is None for q in qs)
+    assert any(q.preemptions for q in qs
+               if q.sla is ServiceLevel.BEST_EFFORT)
+    n_stages = 1 + smoke.DECODE_TOKENS // smoke.DECODE_CHUNK_TOKENS
+    for q in qs:
+        _assert_conserved(q, n_stages)
+    # the decode cache tiles into the kernels' 128-slot blocks
+    assert (eng.models.kv_len + 128) % 128 == 0

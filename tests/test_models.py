@@ -183,3 +183,63 @@ def test_sliding_window_property():
     t3 = t1.at[:, S - 2].set((t1[:, S - 2] + 3) % cfg.vocab_size)
     l3, _ = model.forward(params, t3, dtype=jnp.float32)
     assert float(jnp.max(jnp.abs(l3[:, -1] - l1[:, -1]))) > 1e-4
+
+
+def test_unknown_attention_impl_raises():
+    cfg = get_config("qwen2-0.5b", reduced=True)
+    with pytest.raises(ValueError, match="unknown attention impl"):
+        build_model(cfg, impl="flash")
+
+
+def test_pallas_impl_registers_itself_and_runs_the_kernel(monkeypatch):
+    """Building a model with impl="pallas" imports kernels/ops.py on its
+    own, and the model's attention runs the kernels (interpret mode here)
+    with no fallback to the jnp path."""
+    import sys
+
+    import repro.kernels
+    from repro.models import layers
+
+    # a process in which nobody has imported the kernels yet
+    monkeypatch.delitem(sys.modules, "repro.kernels.ops", raising=False)
+    monkeypatch.delattr(repro.kernels, "ops", raising=False)
+    monkeypatch.delitem(layers.SDPA_IMPL, "pallas", raising=False)
+
+    cfg = get_config("qwen2-0.5b", reduced=True)
+    ref = build_model(cfg, impl="jnp")
+    params = ref.init(KEY)
+    toks = jax.random.randint(KEY, (2, 128), 0, cfg.vocab_size)
+    want, _ = ref.forward(params, toks, dtype=jnp.float32)
+
+    model = build_model(cfg, impl="pallas")
+    assert "repro.kernels.ops" in sys.modules
+    assert layers.SDPA_IMPL["pallas"] is sys.modules["repro.kernels.ops"].sdpa_flash
+
+    def no_fallback(*args):
+        raise AssertionError("pallas attention fell back to the jnp path")
+
+    monkeypatch.setattr(layers, "_sdpa_jnp", no_fallback)
+    got, _ = model.forward(params, toks, dtype=jnp.float32)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4)
+
+
+def test_kernel_and_core_imports_touch_no_backend():
+    """Importing the kernels or the live engine initializes no JAX
+    backend, so a parent process never claims the chip by importing."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    code = (
+        "import repro.kernels.ops, repro.core, repro.core.live\n"
+        "from jax._src import xla_bridge\n"
+        "print('INIT', xla_bridge.backends_are_initialized())\n"
+    )
+    r = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=300, env={**os.environ, "PYTHONPATH": src,
+                          "JAX_PLATFORMS": "cpu"},
+    )
+    assert "INIT False" in r.stdout, r.stdout + r.stderr

@@ -92,8 +92,8 @@ import jax, jax.numpy as jnp
 from repro.launch.programs import build_program
 from repro.perf.hlo import collective_summary
 
-from repro.launch.mesh import mesh_axis_kwargs
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"), **mesh_axis_kwargs(3))
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 3)
 prog = build_program("mixtral-8x7b", "train_4k", mesh, reduced=True)
 with mesh:
     compiled = prog.lower().compile()
@@ -129,8 +129,7 @@ from repro.training.dp_compressed import init_state, make_dp_train_step
 from repro.data.batches import make_batch
 from repro.perf.hlo import collective_summary
 
-from repro.launch.mesh import mesh_axis_kwargs
-mesh = jax.make_mesh((4,), ("data",), **mesh_axis_kwargs(1))
+mesh = jax.make_mesh((4,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
 cfg = get_config("qwen2-0.5b", reduced=True)
 model = build_model(cfg)
 state = init_state(model, jax.random.PRNGKey(0))
