@@ -24,7 +24,8 @@ wall-seconds on a 1-chip worker, at the pool's price).
 
 Placement is the coordinator's: every routing / spill / spill-back
 decision reads ``pool.quote(q)``, never a hardcoded vm/cf branch.
-Used by examples/serve_sla.py, tests/test_live.py, tests/test_system.py.
+Used by chip_smoke.py, examples/serve_sla.py, tests/test_live.py,
+tests/test_system.py.
 """
 from __future__ import annotations
 
