@@ -314,7 +314,6 @@ class ClusterExecutor:
         self.waiting: list[Query] = WaitingQueue(self)
         self._heap: list[tuple[float, int, _Run, int]] = []
         self._seq = itertools.count()
-        self.stages_completed = 0
         #: bumped whenever the pool's planning inputs change (capacity /
         #: slice size); static-quote cache entries are validated against
         #: it together with the calibration version
@@ -796,7 +795,6 @@ class ClusterExecutor:
             q, stage.name, self.name, run.stage_start, t, run.chips,
             run.billed_cs, self.price_per_chip_s, run.stage_retries,
         )
-        self.stages_completed += 1
         if self.stage_observer is not None:
             self.stage_observer(q, stage, ev)
         if q.stage_cursor >= len(run.plan.stages):

@@ -56,6 +56,7 @@ from .pools import (
 from .query import Query, QueryWork
 from .scheduler import QueryCoordinator, ServiceLayer, unpack_fused
 from .sla import Policy, ServiceLevel, SLAConfig
+from .tracing import span
 
 
 #: where a live entry point keeps JAX's persistent compilation cache when
@@ -178,17 +179,18 @@ class _ModelPool:
             lm = self._models.get(key)
             if lm is not None:
                 return lm
-            if arch not in self._archs:
-                self._archs[arch] = self._build(arch)
-            cfg, params, prefill, decode = self._archs[arch]
-            t0 = time.monotonic()
-            toks, kw = _prompt_inputs(cfg, batch, self.prompt_tokens, 0)
-            prefill = prefill.lower(params, toks, kw).compile()
-            tok, cache, _ = prefill(params, toks, kw)
-            decode = decode.lower(params, cache, tok).compile()
-            tok, cache = decode(params, cache, tok)
-            jax.block_until_ready(tok)
-            self.compile_s[key] = time.monotonic() - t0
+            with span("repro.model.compile", arch=arch, batch=batch):
+                if arch not in self._archs:
+                    self._archs[arch] = self._build(arch)
+                cfg, params, prefill, decode = self._archs[arch]
+                t0 = time.monotonic()
+                toks, kw = _prompt_inputs(cfg, batch, self.prompt_tokens, 0)
+                prefill = prefill.lower(params, toks, kw).compile()
+                tok, cache, _ = prefill(params, toks, kw)
+                decode = decode.lower(params, cache, tok).compile()
+                tok, cache = decode(params, cache, tok)
+                jax.block_until_ready(tok)
+                self.compile_s[key] = time.monotonic() - t0
             lm = self._models[key] = _LiveModel(cfg, params, prefill, decode)
             return lm
 
@@ -223,7 +225,6 @@ class LiveExecutor(ClusterExecutor):
     _GUARDED_BY = {
         "running": ("_mu", "_cv"),
         "waiting": ("_mu", "_cv"),
-        "stages_completed": ("_mu", "_cv"),
     }
 
     def __init__(self, spec: PoolSpec, engine: "LiveEngine"):
@@ -346,79 +347,105 @@ class LiveExecutor(ClusterExecutor):
         """Run q's remaining stages on this pool. Returns when q
         finishes, fails, is preempted (re-queued here), or is re-homed.
         ANY exception surfaces as q.state == "failed" — nothing is
-        swallowed, and drain() counts the failure immediately."""
+        swallowed, and drain() counts the failure immediately.
+
+        Spans: ``repro.executor.query`` covers this placement; inside
+        it each stage is a ``repro.executor.stage`` over exactly its
+        billed interval (``t`` is the billed start, engine clock),
+        followed by a ``repro.executor.boundary`` from billing to the
+        preempt/rehome decision."""
         eng = self.engine
-        try:
-            lm = eng.models.ensure(q.work.arch, max(1, q.work.batch))
-            chips = self._plan_chips(q)
-            plan = self.cost_model.plan(q.work, chips)
-            if q.start_time is None:
-                q.start_time = eng.now()
-            eng._note_beat(q)  # heartbeat BEFORE q is visibly "running"
-            q.state = "running"
-            q.cluster = self.name
-            while q.stage_cursor < len(plan.stages):
-                if eng._stop.is_set():
-                    return  # shutdown: abandon between chunks, so a
-                    # timed-out drain never waits out a deep backlog
-                with self._mu:
-                    cur = self.running.get(q.qid)
-                if cur is None or cur[1] is not token or q.state == "failed":
-                    return  # reaped / force-released: a resume (or the
-                    # reaper's _fail) owns this query now
-                stage = plan.stages[q.stage_cursor]
-                start = eng.now()
-                self._run_stage_work(lm, q)
-                finish = eng.now()
-                account_stage(
-                    q, stage=stage.name, cluster=self.name, start=start,
-                    finish=finish, chips=chips,
-                    billed_cs=(finish - start) * chips,
-                    price_per_chip_s=self.price_per_chip_s,
-                )
-                eng._note_beat(q)  # stage-boundary progress heartbeat
-                with self._mu:  # workers finish stages concurrently
-                    self.stages_completed += 1
-                if eng.calibrator is not None:
-                    # live calibration loop: feed the measured stage wall
-                    # and hot-swap the fitted correction at this stage
-                    # boundary — structure is calibration-invariant, so
-                    # the plan below stays index-compatible
-                    eng.calibrator.observe(
-                        self, q.work, q.stage_cursor - 1, chips, finish - start
-                    )
-                    eng.calibrator.maybe_apply(self)
-                if q.stage_cursor >= len(plan.stages):
-                    eng._finish(q)
-                    return
-                if self._boundary_stop(q, token):
-                    return
-        except Exception as err:  # noqa: BLE001 — surfaced, not swallowed
-            eng._fail(q, err)
+        with span("repro.executor.query", qid=q.qid, batch=q.work.batch,
+                  level=q.current_sla.name, cursor=q.stage_cursor):
+            try:
+                lm = eng.models.ensure(q.work.arch, max(1, q.work.batch))
+                chips = self._plan_chips(q)
+                plan = self.cost_model.plan(q.work, chips)
+                if q.start_time is None:
+                    q.start_time = eng.now()
+                eng._note_beat(q)  # heartbeat BEFORE q is visibly "running"
+                q.state = "running"
+                q.cluster = self.name
+                while q.stage_cursor < len(plan.stages):
+                    if eng._stop.is_set():
+                        return  # shutdown: abandon between chunks, so a
+                        # timed-out drain never waits out a deep backlog
+                    with self._mu:
+                        cur = self.running.get(q.qid)
+                    if (cur is None or cur[1] is not token
+                            or q.state == "failed"):
+                        return  # reaped / force-released: a resume (or
+                        # the reaper's _fail) owns this query now
+                    stage = plan.stages[q.stage_cursor]
+                    start = eng.now()
+                    with span("repro.executor.stage", qid=q.qid,
+                              stage=stage.name, t=start):
+                        self._run_stage_work(lm, q)
+                        finish = eng.now()
+                    with span("repro.executor.boundary", qid=q.qid):
+                        account_stage(
+                            q, stage=stage.name, cluster=self.name,
+                            start=start, finish=finish, chips=chips,
+                            billed_cs=(finish - start) * chips,
+                            price_per_chip_s=self.price_per_chip_s,
+                        )
+                        eng._note_beat(q)  # stage-boundary heartbeat
+                        if eng.calibrator is not None:
+                            # live calibration loop: feed the measured
+                            # stage wall and hot-swap the fitted correction
+                            # at this stage boundary — structure is
+                            # calibration-invariant, so the plan below
+                            # stays index-compatible
+                            eng.calibrator.observe(
+                                self, q.work, q.stage_cursor - 1, chips,
+                                finish - start,
+                            )
+                            eng.calibrator.maybe_apply(self)
+                        if q.stage_cursor >= len(plan.stages):
+                            eng._finish(q)
+                            return
+                        if self._boundary_stop(q, token):
+                            return
+            except Exception as err:  # noqa: BLE001 — surfaced, not swallowed
+                eng._fail(q, err)
 
     def _run_stage_work(self, lm: _LiveModel, q: Query) -> None:
         """Execute the real JAX work of stage ``q.stage_cursor`` and
         checkpoint the resulting decode state. Chunk boundaries follow
         CostModel.plan exactly: stage 0 is prefill, stage i > 0 is the
-        next <= decode_chunk_tokens decode steps."""
+        next <= decode_chunk_tokens decode steps.
+
+        Spans, in order: ``repro.stage.inputs`` (prompt tokens, prefill
+        only) or ``repro.stage.checkpoint`` (load), then
+        ``repro.stage.dispatch`` (every call into the compiled program),
+        ``repro.stage.sync`` (the wait for the device) and
+        ``repro.stage.checkpoint`` (save)."""
         eng = self.engine
         batch = max(1, q.work.batch)
         if q.stage_cursor == 0:
-            toks, kw = _prompt_inputs(
-                lm.cfg, batch, q.work.prompt_tokens, seed=q.qid
-            )
-            tok, cache, _ = lm.prefill(lm.params, toks, kw)
-            jax.block_until_ready(tok)
-            eng._save_ckpt(q, DecodeCheckpoint(cache, tok, 0))
+            with span("repro.stage.inputs"):
+                toks, kw = _prompt_inputs(
+                    lm.cfg, batch, q.work.prompt_tokens, seed=q.qid
+                )
+            with span("repro.stage.dispatch"):
+                tok, cache, _ = lm.prefill(lm.params, toks, kw)
+            with span("repro.stage.sync"):
+                jax.block_until_ready(tok)
+            with span("repro.stage.checkpoint"):
+                eng._save_ckpt(q, DecodeCheckpoint(cache, tok, 0))
             return
-        ck = eng._load_ckpt(q)
+        with span("repro.stage.checkpoint"):
+            ck = eng._load_ckpt(q)
         chunk = self.cost_model.decode_chunk_tokens or q.work.output_tokens
         n = min(chunk, q.work.output_tokens - ck.decoded)
         cache, tok = ck.cache, ck.tok
-        for _ in range(n):
-            tok, cache = lm.decode(lm.params, cache, tok)
-        jax.block_until_ready(tok)
-        eng._save_ckpt(q, DecodeCheckpoint(cache, tok, ck.decoded + n))
+        with span("repro.stage.dispatch"):
+            for _ in range(n):
+                tok, cache = lm.decode(lm.params, cache, tok)
+        with span("repro.stage.sync"):
+            jax.block_until_ready(tok)
+        with span("repro.stage.checkpoint"):
+            eng._save_ckpt(q, DecodeCheckpoint(cache, tok, ck.decoded + n))
 
     def _boundary_stop(self, q: Query, token: object) -> bool:
         """Stage-boundary policy, mirroring the simulator's
@@ -519,7 +546,9 @@ class LiveReservedPool(LiveExecutor):
         while not stop.is_set():
             with self._cv:
                 if not self.waiting:
-                    self._cv.wait(timeout=0.05)
+                    # nothing to run until a placement notifies
+                    with span("repro.executor.wait", pool=self.name):
+                        self._cv.wait(timeout=0.05)
                     continue
                 q = self._pop_waiting_locked()
                 token = object()
@@ -758,10 +787,6 @@ class LiveEngine:
     def now(self) -> float:
         return time.monotonic() - self._t0
 
-    @property
-    def vm_run_queue_len(self) -> int:  # legacy observability hook
-        return self.coordinator.vm.run_queue_len
-
     def live_work(self, work: QueryWork) -> QueryWork:
         """Normalize a work descriptor to the shape the live models
         actually run (every query of a batch size shares one program)."""
@@ -868,15 +893,26 @@ class LiveEngine:
 
     # ------------------------------------------------------------------
     def submit(self, q: Query) -> None:
-        q.submit_time = self.now()
-        q.work = self.live_work(q.work)
-        with self._lock:
-            self.service.submit(q, q.submit_time)
+        # span: the caller's whole wait, the engine lock included
+        with span("repro.service.submit", qid=q.qid, level=q.sla.name):
+            q.submit_time = self.now()
+            q.work = self.live_work(q.work)
+            with self._lock:
+                self.service.submit(q, q.submit_time)
 
     def _sched_loop(self) -> None:
         while not self._stop.is_set():
-            with self._lock:
-                self.service.poll(self.now())
+            # span: the time the poll holds the engine lock that every
+            # submit waits on; ``t`` is the poll's engine-clock time (the
+            # released queries' dequeue_time), ``pending`` and ``left``
+            # the pending queries before and after, ``released`` the
+            # programs routed (a fused batch counts once)
+            with self._lock, span("repro.service.poll") as sp:
+                now = self.now()
+                pending = self.service.pending
+                released = self.service.poll(now)
+                sp.set_metadata(t=now, pending=pending, released=released,
+                                left=self.service.pending)
             now_s = self.now()
             if self.cfg.stage_deadline_s is not None:
                 self._reap(now_s)

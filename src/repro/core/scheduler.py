@@ -35,6 +35,7 @@ from . import sanitize
 from .engine import ClusterExecutor
 from .query import Query, QueryWork
 from .sla import Policy, ServiceLevel, SLAConfig
+from .tracing import span
 
 
 def fusion_key(work: QueryWork) -> tuple:
@@ -622,6 +623,20 @@ class QueryCoordinator:
         return merged
 
     def route(self, q: Query, now: float) -> str:
+        """Place q and submit it to the chosen pool; returns the pool's
+        name. Span ``repro.coordinator.route``: the placement, with the
+        placed query's ``qid`` (a fused batch's merged one), its
+        ``members`` (1 unless fused) and the ``pool`` chosen."""
+        with span("repro.coordinator.route") as sp:
+            q, pool = self._place(q, now)
+            sp.set_metadata(qid=q.qid, members=len(q.members or (q,)),
+                            pool=pool.name)
+            pool.submit(q, now)
+            return pool.name
+
+    def _place(self, q: Query, now: float) -> tuple[Query, ClusterExecutor]:
+        """Placement-time fusion, then the policy's pool: the query to
+        submit (q, or a batch merged around it) and where."""
         if (
             self.fusion is not None
             and q.members is None
@@ -710,8 +725,7 @@ class QueryCoordinator:
                 "place", now, qid=q.qid, pool=pool.name,
                 sla=sla.name, cursor=q.stage_cursor,
             )
-        pool.submit(q, now)
-        return pool.name
+        return q, pool
 
 
 class RelaxedScheduler:
