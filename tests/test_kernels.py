@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.decode_attention import decode_attention
+from repro.kernels.decode_attention import KV_BLOCK_BYTES, decode_attention, kv_block
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ops import flash_attention_diff, sdpa_flash
 from repro.kernels.ref import (
@@ -78,6 +78,8 @@ DECODE_CASES = [
     (2, 4, 2, 128, 256, 128, 37),
     (1, 8, 1, 64, 512, 0, 511),  # MQA, nearly-full cache
     (3, 4, 4, 32, 128, 0, 0),  # empty-ish cache (only slot 0)
+    (8, 14, 2, 64, 768, 0, 520),  # qwen2-0.5b served batch: one block per row
+    (2, 16, 8, 128, 1024, 0, 900),  # K 8, hd 128: several blocks per row
 ]
 
 
@@ -101,21 +103,57 @@ def test_decode_attention_matches_oracle(case, dtype):
     )
 
 
-def test_decode_attention_ring_cache():
-    """Ring-buffer slot order (wrapped positions) must not matter."""
-    B, H, K, hd, Smax = 1, 4, 2, 64, 128
+def _ring_case(H, K, hd, Smax, start):
+    """Ring-buffer slot order (wrapped positions) must not matter: absolute
+    positions start..start+Smax-1 stored at slot p % Smax, window Smax."""
     ks = jax.random.split(KEY, 3)
-    q = jax.random.normal(ks[0], (B, H, hd))
-    k = jax.random.normal(ks[1], (B, Smax, K, hd))
-    v = jax.random.normal(ks[2], (B, Smax, K, hd))
-    # wrapped: absolute positions 200..327 stored at slot p % 128
-    abs_pos = jnp.arange(200, 200 + Smax)
+    q = jax.random.normal(ks[0], (1, H, hd))
+    k = jax.random.normal(ks[1], (1, Smax, K, hd))
+    v = jax.random.normal(ks[2], (1, Smax, K, hd))
+    abs_pos = jnp.arange(start, start + Smax)
     slots = abs_pos % Smax
-    pos = jnp.zeros((B, Smax), jnp.int32).at[0, slots].set(abs_pos.astype(jnp.int32))
-    lengths = jnp.array([327], jnp.int32)
-    out = decode_attention(q, k, v, pos, lengths, window=128, interpret=True)
-    ref = decode_attention_ref(q, k, v, pos, lengths, window=128)
+    pos = jnp.zeros((1, Smax), jnp.int32).at[0, slots].set(abs_pos.astype(jnp.int32))
+    lengths = jnp.array([start + Smax - 1], jnp.int32)
+    out = decode_attention(q, k, v, pos, lengths, window=Smax, interpret=True)
+    ref = decode_attention_ref(q, k, v, pos, lengths, window=Smax)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-4)
+
+
+def test_decode_attention_ring_cache():
+    _ring_case(H=4, K=2, hd=64, Smax=128, start=200)
+
+
+def test_decode_attention_ring_cache_over_blocks():
+    """Two 256-slot blocks with the wrap inside the first: the streaming
+    softmax carries across them."""
+    assert kv_block(512, 8 * 128, 4) == 256
+    _ring_case(H=16, K=8, hd=128, Smax=512, start=600)
+
+
+@pytest.mark.parametrize("smax,K,hd,itemsize", [
+    (768, 2, 64, 2),  # qwen2-0.5b served cache
+    (768, 2, 64, 4),
+    (128, 1, 64, 2),
+    (4096, 8, 128, 2),  # internlm2 / granite / mixtral at a long context
+    (4096, 8, 128, 4),
+    (8192, 4, 256, 2),  # gemma2
+    (1152, 8, 128, 2),  # 1152 = 9 * 128: the largest fitting divisor is 384
+])
+def test_kv_block_tiles_the_cache(smax, K, hd, itemsize):
+    bk = kv_block(smax, K * hd, itemsize)
+    assert smax % bk == 0
+    assert bk == smax or bk % 128 == 0
+    assert bk * K * hd * itemsize <= KV_BLOCK_BYTES
+    # no larger legal block fits the budget
+    for b in range(bk + 128, smax + 1, 128):
+        if smax % b == 0:
+            assert b * K * hd * itemsize > KV_BLOCK_BYTES
+
+
+def test_kv_block_is_the_whole_cache_at_the_served_shape():
+    """qwen2-0.5b's served decode (Smax 768, K 2, hd 64, bf16): one grid
+    step per batch row."""
+    assert kv_block(768, 2 * 64, 2) == 768
 
 
 SSD_CASES = [

@@ -19,12 +19,13 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
-from repro.kernels.decode_attention import decode_attention
+from repro.kernels.decode_attention import decode_attention, kv_block
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ssd_scan import ssd_scan
 
 QWEN = get_config("qwen2-0.5b")  # 14 query heads, 2 KV heads, head dim 64
 MAMBA = get_config("mamba2-2.7b")
+INTERNLM = get_config("internlm2-1.8b")  # 16 query heads, 8 KV heads, head dim 128
 
 
 @pytest.fixture(scope="module")
@@ -66,10 +67,10 @@ def test_flash_attention_compiles_at_qwen2_prefill(one_chip, batch, seq):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("batch", [1, 4])
+@pytest.mark.parametrize("batch", [1, 4, 8])
 def test_decode_attention_compiles_at_qwen2_decode(one_chip, batch):
     H, K, hd = QWEN.num_heads, QWEN.num_kv_heads, QWEN.head_dim
-    smax = 512
+    smax = 768  # the served cache: prompt 512 + output 16, rounded, + 128
     fn = functools.partial(decode_attention, interpret=False)
     text = _compiled_text(
         fn, one_chip,
@@ -78,6 +79,24 @@ def test_decode_attention_compiles_at_qwen2_decode(one_chip, batch):
         ((batch, smax, K, hd), jnp.bfloat16),
         ((batch, smax), jnp.int32),
         ((batch,), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_decode_attention_compiles_over_several_blocks(one_chip):
+    """K 8 / hd 128 at a 4096-slot cache streams through 512-slot blocks:
+    the lane slices are 128-aligned and the blocks fit VMEM."""
+    B, H, K, hd = 2, INTERNLM.num_heads, INTERNLM.num_kv_heads, INTERNLM.head_dim
+    smax = 4096
+    assert kv_block(smax, K * hd, 2) < smax
+    fn = functools.partial(decode_attention, window=1024, interpret=False)
+    text = _compiled_text(
+        fn, one_chip,
+        ((B, H, hd), jnp.bfloat16),
+        ((B, smax, K, hd), jnp.bfloat16),
+        ((B, smax, K, hd), jnp.bfloat16),
+        ((B, smax), jnp.int32),
+        ((B,), jnp.int32),
     )
     assert "tpu_custom_call" in text
 
