@@ -21,3 +21,8 @@ def schedule(params: dict, seconds: float, seed: int) -> np.ndarray:
 
 def rate_qps(params: dict) -> float:
     return float(params["rate_qps"])
+
+
+def tiny(params: dict) -> dict:
+    """The same process at the CPU tests' size (a window of 1.5 s)."""
+    return {**params, "rate_qps": 10.0}

@@ -24,3 +24,9 @@ def schedule(params: dict, seconds: float, seed: int) -> np.ndarray:
 
 def rate_qps(params: dict) -> float:
     return int(params["per_round"]) / float(params["period_s"])
+
+
+def tiny(params: dict) -> dict:
+    """The same rounds at the CPU tests' size (a window of 1.5 s): short
+    rounds whose bursts still queue and fuse."""
+    return {**params, "period_s": 0.5, "spread_s": 0.05, "per_round": 12}

@@ -73,14 +73,18 @@ def structural(run, qs, eng, require_chip: bool) -> dict:
 
 def sample_rows(run, seed: int) -> list:
     """(execution qid, row, member qid) of the rows to check, drawn from
-    the seed among finished window queries: half from fused batches, half
-    from queries that ran alone, and always the last row of the largest
-    batch, the row that a batch cut short or mixed up gets wrong."""
+    the seed among finished window queries: half from programs of more
+    than one row (fused queries, or a query's own batch), half from
+    programs of one, and always the last row of the largest batch, the
+    row that a batch cut short or mixed up gets wrong. Each member holds
+    the traffic's ``batch`` rows, in member order."""
     done = {r.qid for r in run.queries if r.state == "done"}
+    b = int(run.traffic["batch"])
     fused, alone = [], []
     for ex in run.executions.values():
-        rows = [(ex.qid, j, m) for j, m in enumerate(ex.members) if m in done]
-        (fused if len(ex.members) > 1 else alone).extend(rows)
+        rows = [(ex.qid, j * b + i, m) for j, m in enumerate(ex.members)
+                for i in range(b) if m in done]
+        (fused if len(ex.members) * b > 1 else alone).extend(rows)
     want = int(run.traffic["check_rows"])
     rng = np.random.default_rng([int(seed) % (1 << 63), 11])
     picked = []

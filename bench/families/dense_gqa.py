@@ -9,6 +9,9 @@ RMSNorm and the output head (the embedding, transposed, where tied).
 
 This file holds, for that description alone:
 
+* ``PROGRAM_FIELDS``: which field of the program's ``ModelConfig`` each
+  key of the configuration file sets. Set-up compares the built program
+  with it, and a ``cut`` reaches the program through it;
 * ``make_weights``: every weight from the seed, on the device, in one
   jitted call, in the published layout and the served dtype;
 * ``to_program``: the same weights in the layout the served program
@@ -38,6 +41,22 @@ HI = jax.lax.Precision.HIGHEST
 #: initial values (1 and 0): non-trivial, so the check sees them applied
 NORM_SPREAD = 0.1
 BIAS_SCALE = 0.5
+
+
+#: ModelConfig field <- configuration file key (a dot steps into a group)
+PROGRAM_FIELDS = {
+    "num_layers": "num_hidden_layers",
+    "d_model": "hidden_size",
+    "num_heads": "num_attention_heads",
+    "num_kv_heads": "num_key_value_heads",
+    "head_dim": "model.head_dim",
+    "d_ff": "intermediate_size",
+    "vocab_size": "vocab_size",
+    "qkv_bias": "model.qkv_bias",
+    "tie_embeddings": "tie_word_embeddings",
+    "rope_theta": "rope_theta",
+    "norm_eps": "rms_norm_eps",
+}
 
 
 def dims(config: dict) -> dict:

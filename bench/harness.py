@@ -55,7 +55,7 @@ class Execution:
     """One executed program run: a query, or a fused batch of them."""
 
     qid: int
-    members: list  # member qids, one per batch row, in row order
+    members: list  # member qids in row order, each the traffic's batch rows
     prompt: object = None  # (B, S) device array fed to prefill
     tokens: list = field(default_factory=list)  # (B, 1) device arrays
 
@@ -72,6 +72,8 @@ class Run:
     dims: dict
     peaks: dict
     prompt_tokens: int
+    #: where the cell's families, metrics and kernels are loaded from
+    bench_dir: Path
     setup_s: float = 0.0
     window: tuple = (0.0, 0.0)  # engine clock
     queries: list = field(default_factory=list)
@@ -266,25 +268,63 @@ def build_engine(config: dict, traffic: dict):
     return LiveEngine(cfg)
 
 
-def check_program_config(prog_cfg, d: dict) -> list:
-    """Widths the program built against the configuration file's."""
-    want = {"num_layers": d["L"], "d_model": d["D"], "num_heads": d["H"],
-            "num_kv_heads": d["K"], "head_dim": d["hd"], "d_ff": d["F"],
-            "vocab_size": d["V"], "qkv_bias": d["bias"],
-            "tie_embeddings": d["tied"], "rope_theta": d["theta"]}
-    return [f"{k}: program {getattr(prog_cfg, k)!r} != config {v!r}"
-            for k, v in want.items() if getattr(prog_cfg, k) != v]
+def check_program_config(prog_cfg, config: dict, family) -> list:
+    """The program as built against the configuration file, its cut
+    applied: every field of the family's ``PROGRAM_FIELDS``."""
+    bad = []
+    for f, key in family.PROGRAM_FIELDS.items():
+        want, got = manifest.file_value(config, key), getattr(prog_cfg, f)
+        if got != want:
+            bad.append(f"{f}: program {got!r} != config {key} {want!r}")
+    return bad
+
+
+def program_overrides(config: dict, family) -> dict:
+    """The ``ModelConfig`` fields that the configuration's cut changes,
+    with the values kept on this chip; empty where there is no cut."""
+    cut = config.get("cut") or {}
+    field_of = {key: f for f, key in family.PROGRAM_FIELDS.items()}
+    unknown = sorted(set(cut) - set(field_of))
+    if unknown:
+        raise RuntimeError(f"the family's PROGRAM_FIELDS sets no field from"
+                           f" the cut's {unknown}")
+    return {field_of[k]: v for k, v in cut.items()}
+
+
+@contextlib.contextmanager
+def _program_cut(arch: str, overrides: dict):
+    """While open, the program's ``_ModelPool._build`` builds ``arch`` with
+    ``overrides`` applied to its config (``ModelConfig.replace``), and
+    nothing else changes. The engine takes no cut of its own yet."""
+    if not overrides:
+        yield
+        return
+    from repro.core import live
+
+    published = live.get_config
+
+    def get_config(name, reduced=False):
+        cfg = published(name, reduced)
+        return cfg.replace(**overrides) if name == arch else cfg
+
+    live.get_config = get_config
+    try:
+        yield
+    finally:
+        live.get_config = published
 
 
 def install_weights(eng, config: dict, family, seed: int) -> None:
-    """Build the arch's served programs, then serve the benchmark's
-    weights (made from the seed) in place of the program's own."""
+    """Build the arch's served programs, cut as the configuration says,
+    then serve the benchmark's weights (made from the seed) in place of
+    the program's own."""
     import jax
 
     arch = config["model"]["arch"]
     models = eng.models
-    prog_cfg, prog_params, prefill, decode = models._build(arch)
-    bad = check_program_config(prog_cfg, family.dims(config))
+    with _program_cut(arch, program_overrides(config, family)):
+        prog_cfg, prog_params, prefill, decode = models._build(arch)
+    bad = check_program_config(prog_cfg, config, family)
     if bad:
         raise RuntimeError("program config differs from the file: " + "; ".join(bad))
     want = jax.tree.map(lambda a: (a.shape, a.dtype), prog_params)
@@ -340,15 +380,15 @@ def run_cell(cell: manifest.Cell, seed: int, seconds: float, trace: bool,
     from repro.core.sla import ServiceLevel
 
     log = log or (lambda s: print(s, file=sys.stderr, flush=True))
-    config, traffic = cell.config, cell.traffic
-    family = manifest.load_module("families", config["model"]["family"])
-    arrivals = manifest.load_module("arrivals", traffic["arrivals"])
+    config, traffic, bench_dir = cell.config, cell.traffic, cell.bench_dir
+    family = manifest.load_module("families", config["model"]["family"], bench_dir)
+    arrivals = manifest.load_module("arrivals", traffic["arrivals"], bench_dir)
     dev = jax.devices()[0]
-    peaks = (manifest.load_peaks(dev.device_kind) if require_chip
+    peaks = (manifest.load_peaks(dev.device_kind, bench_dir) if require_chip
              else {"bf16_flops_per_s": 1.0, "hbm_bytes_per_s": 1.0})
     run = Run(cell=cell.name, seed=seed, seconds=seconds, config=config,
               traffic=traffic, dims=family.dims(config), peaks=peaks,
-              prompt_tokens=int(traffic["prompt_tokens"]))
+              prompt_tokens=int(traffic["prompt_tokens"]), bench_dir=bench_dir)
     compiles = _count_backend_compiles()
     t_phase = [time.monotonic()]
 

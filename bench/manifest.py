@@ -2,10 +2,19 @@
 
 ``BENCHMARK.json`` names configurations, traffic mixes and metrics; each
 lives in a file of its own under ``bench/``. Nothing here lists a cell, a
-metric or a kernel: adding a file and an entry in the manifest adds it.
+metric, a kernel or a model family: adding a file and an entry in the
+manifest adds it. A cell records the directory it was loaded from, and
+every module of the cell is loaded from there.
+
+A configuration file holds the published config under its own key names.
+Where the model does not fit one chip whole, the file's ``cut`` holds the
+values kept on this chip, in the same key names (``{"num_hidden_layers":
+4}``); every key of it is named in the file's ``reduced``, and its
+``assumed`` says, under the same key, what deployment the cut stands for.
 """
 from __future__ import annotations
 
+import copy
 import importlib.util
 import json
 from dataclasses import dataclass
@@ -53,16 +62,50 @@ def load_peaks(device_kind: str, bench_dir: Path = BENCH_DIR) -> dict:
     return table[device_kind]
 
 
+def file_value(config: dict, key: str):
+    """A configuration file's value at ``key``; a dot steps into a group
+    (``model.head_dim``)."""
+    node = config
+    for part in key.split("."):
+        if part not in node:
+            raise KeyError(f"configuration {config.get('name')!r} has no {key!r}")
+        node = node[part]
+    return node
+
+
+def apply_cut(config: dict) -> dict:
+    """The configuration as this chip runs it: the file's ``cut`` over the
+    published values, which stay beside it under ``published``. A file
+    with no ``cut`` comes back as it is."""
+    cut = config.get("cut")
+    if not cut:
+        return config
+    name = config.get("name")
+    unnamed = sorted(set(cut) - set(config.get("reduced", [])))
+    if unnamed:
+        raise ValueError(f"configuration {name!r} cuts {unnamed} without"
+                         " naming them in its reduced")
+    unknown = sorted(set(cut) - set(config))
+    if unknown:
+        raise ValueError(f"configuration {name!r} cuts {unknown}, which it"
+                         " does not hold")
+    out = copy.deepcopy(config)
+    out["published"] = {k: config[k] for k in cut}
+    out.update(cut)
+    return out
+
+
 @dataclass
 class Cell:
     """One workload of the manifest with everything it names, loaded."""
 
     name: str
     chips: int
-    config: dict
+    config: dict  # with its cut applied (``apply_cut``)
     traffic: dict
     end_to_end: list  # manifest entries reported with --trace 0
     per_layer: list  # manifest entries reported with --trace 1
+    bench_dir: Path  # where its modules are loaded from
 
 
 def _applies(metric: dict, cell: str) -> bool:
@@ -82,8 +125,9 @@ def load_cell(name: str, manifest: dict | None = None,
     return Cell(
         name=name,
         chips=int(w["chips"]),
-        config=config,
+        config=apply_cut(config),
         traffic=traffic,
         end_to_end=[m for m in manifest["end_to_end"] if _applies(m, name)],
         per_layer=[m for m in manifest["per_layer"] if _applies(m, name)],
+        bench_dir=bench_dir,
     )

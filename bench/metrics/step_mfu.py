@@ -7,7 +7,7 @@ from bench.reduce import module_time
 
 
 def read(run):
-    fam = load_module("families", run.config["model"]["family"])
+    fam = load_module("families", run.config["model"]["family"], run.bench_dir)
     c = run.config
     work = {
         "jit_prefill": [fam.prefill_flops(c, b, run.prompt_tokens)

@@ -36,7 +36,7 @@ def kernel_roofline(run, kernel: str) -> float | None:
         return None
     from .manifest import load_module
 
-    k = load_module("kernels", kernel)
+    k = load_module("kernels", kernel, run.bench_dir)
     calls = k.calls(run)
     n, secs = tr.op_time(run.trace, k.NAMES)
     if not calls or n == 0 or secs <= 0:

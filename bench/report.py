@@ -14,7 +14,7 @@ def metrics(entries: list, run) -> tuple[dict, list]:
     left out of the line."""
     out, missing = {}, []
     for m in entries:
-        v = manifest.load_module("metrics", m["name"]).read(run)
+        v = manifest.load_module("metrics", m["name"], run.bench_dir).read(run)
         if v is None:
             missing.append(m["name"])
         else:

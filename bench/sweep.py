@@ -38,7 +38,7 @@ def main(argv=None) -> int:
     cell = manifest.load_cell(args.workload)
     cli.prepare(cell.chips)
     base = cell.traffic
-    arrivals = manifest.load_module("arrivals", base["arrivals"])
+    arrivals = manifest.load_module("arrivals", base["arrivals"], cell.bench_dir)
     t0 = T_PROCESS
     for v in args.values:
         cell.traffic = copy.deepcopy(base)

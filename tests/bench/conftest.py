@@ -14,8 +14,12 @@ import pytest  # noqa: E402
 @pytest.fixture
 def tiny_cell():
     """A cell of the manifest cut to a size the CPU runs in seconds: the
-    program's reduced float32 widths, the jnp attention path, short
-    prompts, a short window with bursts that fuse, and fuse_max 2.
+    program's reduced float32 widths written into its configuration
+    through the family's ``PROGRAM_FIELDS``, the jnp attention path,
+    short prompts, the arrival process's own ``tiny`` parameters (a
+    short window with bursts that fuse), and fuse_max 2. A ``cut`` is
+    for the published sizes and goes, unless ``keep_cut``: then it is
+    applied to the reduced program as it is to the published one.
 
     The logit-gap limit is this size's own: the float32 program reads
     at most 1e-3 here and the fp8 control about 0.26, so 0.1 lies
@@ -24,25 +28,30 @@ def tiny_cell():
     from bench import manifest
     from repro.configs import get_config
 
-    def make(workload: str) -> manifest.Cell:
-        cell = manifest.load_cell(workload)
+    def make(workload: str, bench_json: dict | None = None,
+             bench_dir=manifest.BENCH_DIR, keep_cut: bool = False) -> manifest.Cell:
+        cell = manifest.load_cell(workload, bench_json, bench_dir)
         c = copy.deepcopy(cell.config)
+        cut = c.pop("cut", None)
+        c.pop("published", None)
+        family = manifest.load_module("families", c["model"]["family"], bench_dir)
         r = get_config(c["model"]["arch"], reduced=True)
-        c.update(hidden_size=r.d_model, intermediate_size=r.d_ff,
-                 num_attention_heads=r.num_heads, num_key_value_heads=r.num_kv_heads,
-                 num_hidden_layers=r.num_layers, vocab_size=r.vocab_size,
-                 rms_norm_eps=r.norm_eps)
-        c["model"].update(head_dim=r.head_dim, published_widths=False,
-                          dtype="float32", impl="jnp")
+        for field, key in family.PROGRAM_FIELDS.items():
+            *groups, leaf = key.split(".")
+            node = c
+            for g in groups:
+                node = node[g]
+            node[leaf] = getattr(r, field)
+        if keep_cut and cut:
+            c = manifest.apply_cut(dict(c, cut=cut))
+        c["model"].update(published_widths=False, dtype="float32", impl="jnp")
         c["deployment"]["fuse_max"] = 2
         c["check"]["logit_gap_limit"] = 0.1
         t = copy.deepcopy(cell.traffic)
         t.update(prompt_tokens=32, output_tokens=4, check_rows=6,
                  trace_window=[0.0, 1.0])
-        if t["arrivals"] == "rounds":
-            t["params"].update(period_s=0.5, spread_s=0.05, per_round=12)
-        else:
-            t["params"].update(rate_qps=10.0)
+        arrivals = manifest.load_module("arrivals", t["arrivals"], bench_dir)
+        t["params"] = arrivals.tiny(t["params"])
         cell.config, cell.traffic = c, t
         return cell
 

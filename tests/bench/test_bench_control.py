@@ -6,10 +6,11 @@ from types import SimpleNamespace
 
 import pytest
 
-from bench import check
+from bench import check, manifest
 
 
-@pytest.mark.parametrize("workload", ["qwen2-0.5b.dashboard"])
+@pytest.mark.parametrize(
+    "workload", [w["name"] for w in manifest.load_manifest()["workloads"]])
 def test_sound_run_passes_and_control_fails(workload, tiny_cell, run_tiny):
     cell = tiny_cell(workload)
     run = run_tiny(cell, control=True)
@@ -36,9 +37,24 @@ def test_sample_holds_the_last_row_of_the_largest_batch(seed):
     execs = {1: _ex(1, 1), 2: _ex(2, 3), 3: _ex(3, 8), 4: _ex(4, 1), 5: _ex(5, 2)}
     done = [m for e in execs.values() for m in e.members]
     run = SimpleNamespace(
-        executions=execs, traffic={"check_rows": 4},
+        executions=execs, traffic={"check_rows": 4, "batch": 1},
         queries=[SimpleNamespace(qid=m, state="done") for m in done])
     rows = check.sample_rows(run, seed)
     assert (3, 7, 307) in rows
     assert len(rows) == 4 and len(set(rows)) == 4
     assert any(len(execs[e].members) == 1 for e, _, _ in rows)
+
+
+def test_sample_reaches_every_row_of_queries_of_several_rows():
+    """A query of the traffic's ``batch`` rows holds that many rows of its
+    program, fused or alone; the sample reaches each, and the last row of
+    the largest batch."""
+    execs = {1: _ex(1, 1), 2: _ex(2, 2)}
+    done = [m for e in execs.values() for m in e.members]
+    run = SimpleNamespace(
+        executions=execs, traffic={"check_rows": 12, "batch": 4},
+        queries=[SimpleNamespace(qid=m, state="done") for m in done])
+    rows = check.sample_rows(run, 3)
+    assert rows[0] == (2, 7, 201)
+    assert sorted(rows) == [(1, i, 100) for i in range(4)] + [
+        (2, i, 200 + i // 4) for i in range(8)]

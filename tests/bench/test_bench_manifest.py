@@ -26,9 +26,63 @@ def test_every_name_resolves_to_a_file(bench_json):
         assert cell.end_to_end and cell.per_layer
     for m in bench_json["end_to_end"] + bench_json["per_layer"]:
         assert callable(manifest.load_module("metrics", m["name"]).read)
-    for name in ("flash_attention", "decode_attention"):
-        k = manifest.load_module("kernels", name)
-        assert k.NAMES and callable(k.cost) and callable(k.calls)
+
+
+def test_every_kernel_counts_its_work():
+    files = sorted((manifest.BENCH_DIR / "kernels").glob("*.py"))
+    assert files
+    for f in files:
+        k = manifest.load_module("kernels", f.stem)
+        assert k.NAMES and callable(k.cost) and callable(k.calls), f.name
+
+
+def test_every_family_declares_its_program_fields():
+    """Each family says which program field each of its file keys sets,
+    and every key it names is in each configuration file of the family."""
+    files = sorted((manifest.BENCH_DIR / "families").glob("*.py"))
+    assert files
+    configs = [json.loads(p.read_text())
+               for p in (manifest.BENCH_DIR / "configs").glob("*.json")]
+    for f in files:
+        fam = manifest.load_module("families", f.stem)
+        fields = fam.PROGRAM_FIELDS
+        assert fields and "num_layers" in fields, f.name
+        for cfg in configs:
+            if cfg["model"]["family"] == f.stem:
+                for key in fields.values():
+                    manifest.file_value(cfg, key)
+
+
+def test_every_cell_builds_its_configuration(bench_json):
+    """The program's published config, cut as the file says, agrees with
+    the configuration file in every field of the family's table: what
+    set-up checks on the chip, without building the model."""
+    from bench import harness
+    from repro.configs import get_config
+
+    for w in bench_json["workloads"]:
+        cfg = manifest.load_cell(w["name"], bench_json).config
+        fam = manifest.load_module("families", cfg["model"]["family"])
+        prog = get_config(cfg["model"]["arch"]).replace(
+            **harness.program_overrides(cfg, fam))
+        assert harness.check_program_config(prog, cfg, fam) == [], w["name"]
+
+
+def test_every_cut_key_is_named_in_reduced(bench_json):
+    """A configuration's cut names only keys it lists in ``reduced`` (as
+    the manifest does), with the deployment each stands for under
+    ``assumed``, and never a width."""
+    width = re.compile(r"_dim$|_rank$|hidden_size|intermediate_size|latent"
+                       r"|state|proj|head_|expan|per_tok|top_k")
+    for p in (manifest.BENCH_DIR / "configs").glob("*.json"):
+        cfg = json.loads(p.read_text())
+        for key in cfg.get("cut", {}):
+            assert key in cfg["reduced"], (p.name, key)
+            assert key in cfg["assumed"], (p.name, key)
+            assert key in cfg and not width.search(key), (p.name, key)
+    for c in bench_json["configs"]:
+        cfg = json.loads((manifest.ROOT / c["file"]).read_text())
+        assert set(cfg.get("cut", {})) <= set(c["reduced"])
 
 
 def test_unknown_device_kind_is_refused():
@@ -103,7 +157,8 @@ def test_adding_files_adds_a_cell_and_a_metric(tmp_path, bench_json):
 def _empty_run():
     return SimpleNamespace(trace=None, queries=[], config={}, seconds=1.0,
                            finished_in_window=lambda: [], checks={},
-                           memory_peak_bytes=0, metrics_missing=[])
+                           memory_peak_bytes=0, metrics_missing=[],
+                           bench_dir=manifest.BENCH_DIR)
 
 
 def test_metrics_that_find_nothing_are_left_out():
